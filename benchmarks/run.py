@@ -15,6 +15,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     ("table1", "benchmarks.bench_table1_rtc"),
     ("fig3", "benchmarks.bench_fig3_async_sched"),
@@ -65,6 +67,7 @@ def main() -> None:
                     help="directory for BENCH_<key>.json (default: cwd)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -47,9 +47,11 @@ back on the free list.
 """
 from __future__ import annotations
 
+import logging
 import re
 import threading
 import time
+import traceback
 
 import jax
 from collections import deque
@@ -72,6 +74,7 @@ from repro.engine import EngineConfig, FlowServe, Request, SamplingParams
 from repro.engine.flowserve import Completion
 
 _PD_GROUP_RE = re.compile(r"^(\d+)p(\d+)d$")
+_log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -175,6 +178,7 @@ class ServingJobEngine:
         # freed window before either registers
         self._window_lock = threading.Lock()
         self._reserved_windows: set = set()
+        self.window_fallbacks = 0               # TEs co-resident on window 0
         self.engines: List[FlowServe] = []
         self.policy = policy
         self.scaler = scaler
@@ -189,6 +193,9 @@ class ServingJobEngine:
         #                                         initial fleet gets hooks
         self.admission_limit = admission_limit  # queued-per-serving-TE cap
         self.rejections: List[Dict[str, Any]] = []
+        # every exception a unit raised: unit, type, message, traceback —
+        # recovery hides a failure from the requests, never from the books
+        self.unit_failures: List[Dict[str, Any]] = []
         self._parked: List[Request] = []        # recovered, no survivor yet
         self._xfer_retry: Dict[str, Tuple[int, int]] = {}  # rid -> (n, due)
         self.xfer_retries = 0
@@ -298,6 +305,9 @@ class ServingJobEngine:
                 self._offset_cursor += width
                 self._reserved_windows.add(off)
                 return off, True
+            self.window_fallbacks += 1
+            _log.warning("fleet outgrew %d visible devices: TE shares "
+                         "window 0", jax.device_count())
             return 0, False
 
     def _commit_window(self, name: str, off: int, owned: bool) -> None:
@@ -599,6 +609,10 @@ class ServingJobEngine:
     def _on_unit_failure(self, te_id: str, exc: BaseException) -> None:
         """Detect → contain → recover for one failed fleet unit (§11).
 
+        Detection: the failure is kept in ``unit_failures`` and logged, so
+        a run that recovered from it — or a short run that lost requests
+        to it — still shows what was raised.
+
         Containment: the unit walks FAILED → RELEASED, leaves routing
         (``admitting`` is False the moment it leaves SERVING; the handle
         is removed from both schedulers' views), and its device windows
@@ -615,6 +629,12 @@ class ServingJobEngine:
         least-loaded surviving prefill-capable engine from the PROMPT via
         ``_resubmit`` (req_id + arrival preserved, restart counted); with
         no survivor it parks until capacity returns."""
+        self.unit_failures.append({
+            "unit": te_id, "step": self.steps, "type": type(exc).__name__,
+            "message": str(exc),
+            "traceback": "".join(traceback.format_exception(exc))})
+        _log.error("fleet unit %s failed at plane step %d: %s: %s", te_id,
+                   self.steps, type(exc).__name__, exc)
         handle = next((h for h in self._handles if h.te_id == te_id), None)
         if handle is None:
             return                        # already quarantined

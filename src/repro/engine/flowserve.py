@@ -334,7 +334,15 @@ class FlowServe:
         if self.runner_kind == "slot" and self._state_cache is not None:
             self._try_state_reuse(seq)
         self.scheduler.admit(seq)
+        self._drop_idle_plan()
         return req.req_id
+
+    def _drop_idle_plan(self) -> None:
+        """An async plan prepared while the engine was idle is empty, and
+        stale once work arrives: running it would spend the next step on
+        nothing."""
+        if self._next_plan is not None and self._next_plan.is_empty():
+            self._next_plan = None
 
     @_executor_safe
     def has_work(self) -> bool:
@@ -508,47 +516,17 @@ class FlowServe:
             self._prefill_legacy([(s, st, ch) for s, st, ch, _ in packed])
             return
 
-        # ---- pack the flat ragged token stream (host-side, numpy)
-        sb = pow2_bucket(max(self.ecfg.max_prefill_seqs, len(packed)))
-        pb = pow2_bucket(max(len(s.pages) for s, _, _, _ in packed))
-        flat_t, flat_p, flat_pg, flat_sl, rows = [], [], [], [], []
-        final_idx = np.zeros((sb,), np.int32)
-        temps = np.zeros((sb,), np.float32)
-        top_ps = np.ones((sb,), np.float32)
-        for i, (seq, start, chunk, ext) in enumerate(packed):
-            toks = chunk + ([seq.tokens[-1]] if ext else [])
-            row = seq.pages + [scratch] * (pb - len(seq.pages))
-            for j, t in enumerate(toks):
-                pos = start + j
-                flat_t.append(t)
-                flat_p.append(pos)
-                flat_pg.append(seq.pages[pos // ps])
-                flat_sl.append(pos % ps)
-                rows.append(row)
-            final_idx[i] = len(flat_t) - 1
-            if ext:
-                sp = self.sample_params[seq.seq_id]
-                temps[i] = sp.temperature
-                top_ps[i] = sp.top_p
-        tb = pow2_bucket(len(flat_t))
-        pad_row = [scratch] * pb
-        while len(flat_t) < tb:
-            flat_t.append(0)
-            flat_p.append(0)
-            flat_pg.append(scratch)
-            flat_sl.append(0)
-            rows.append(pad_row)
-
         if self._prefill_key is None:
             self._key, self._prefill_key = jax.random.split(self._key)
+        rows = []
+        for seq, start, chunk, ext in packed:
+            sp = self.sample_params[seq.seq_id]
+            rows.append((seq.pages, start,
+                         chunk + ([seq.tokens[-1]] if ext else []),
+                         sp.temperature if ext else 0.0,
+                         sp.top_p if ext else 1.0))
         _, toks_dev, self._prefill_key = self.runner.prefill_ragged(
-            jnp.asarray(np.asarray(flat_t, np.int32)),
-            jnp.asarray(np.asarray(flat_p, np.int32)),
-            jnp.asarray(np.asarray(flat_pg, np.int32)),
-            jnp.asarray(np.asarray(flat_sl, np.int32)),
-            jnp.asarray(np.asarray(rows, np.int32)),
-            jnp.asarray(final_idx), jnp.asarray(temps), jnp.asarray(top_ps),
-            self._prefill_key)
+            *self._pack_ragged(rows, scratch), self._prefill_key)
         self.prefill_dispatches += 1
 
         # ---- commit: lengths, extension first-tokens, queue transitions
@@ -580,6 +558,66 @@ class FlowServe:
                     arrival=req.arrival, n_prompt=seq.n_prompt))
                 self.scheduler.on_finished(seq)
                 self.release_request(seq.seq_id)
+
+    def _pack_ragged(self, rows, scratch: int):
+        """Pack prefill rows — ``(pages, start, tokens, temperature,
+        top_p)`` each — into the padded pow2-bucketed operands of
+        ``runner.prefill_ragged`` (host-side, numpy): the flat token stream
+        with per-token (position, page, slot), per-token block-table rows,
+        each row's final flat index, and per-row sampling params. Padding
+        tokens park on the ``scratch`` page at position 0."""
+        ps = self.ecfg.page_size
+        sb = pow2_bucket(max(self.ecfg.max_prefill_seqs, len(rows)))
+        pb = pow2_bucket(max(len(pages) for pages, *_ in rows))
+        flat_t, flat_p, flat_pg, flat_sl, bt_tok = [], [], [], [], []
+        final_idx = np.zeros((sb,), np.int32)
+        temps = np.zeros((sb,), np.float32)
+        top_ps = np.ones((sb,), np.float32)
+        for i, (pages, start, toks, temp, top_p) in enumerate(rows):
+            row = pages + [scratch] * (pb - len(pages))
+            for j, t in enumerate(toks):
+                pos = start + j
+                flat_t.append(t)
+                flat_p.append(pos)
+                flat_pg.append(pages[pos // ps])
+                flat_sl.append(pos % ps)
+                bt_tok.append(row)
+            final_idx[i] = len(flat_t) - 1
+            temps[i] = temp
+            top_ps[i] = top_p
+        tb = pow2_bucket(len(flat_t))
+        pad = tb - len(flat_t)
+        flat_t += [0] * pad
+        flat_p += [0] * pad
+        flat_pg += [scratch] * pad
+        flat_sl += [0] * pad
+        bt_tok += [[scratch] * pb] * pad
+        return (*(jnp.asarray(np.asarray(a, np.int32))
+                  for a in (flat_t, flat_p, flat_pg, flat_sl, bt_tok)),
+                jnp.asarray(final_idx), jnp.asarray(temps),
+                jnp.asarray(top_ps))
+
+    @_executor_safe
+    def prompt_logits(self, tokens: List[int]) -> jax.Array:
+        """Next-token logits (padded vocab) after ``tokens``, computed by
+        the engine's own batched prefill microkernel in ONE dispatch over
+        pages borrowed from the pool and freed again: no sequence, prefix
+        entry or sampling state changes. This is the engine side of a
+        logits-vs-reference check."""
+        if self.runner_kind != "paged" or not self.ecfg.batched_prefill:
+            raise NotImplementedError(
+                f"prompt_logits needs the paged batched prefill path, not "
+                f"{self.runner_kind!r}")
+        pages = self.pool.alloc(pages_needed(len(tokens),
+                                             self.ecfg.page_size))
+        try:
+            ops = self._pack_ragged([(pages, 0, list(tokens), 0.0, 1.0)],
+                                    self.pool.scratch_page())
+            logits, _, _ = self.runner.prefill_ragged(
+                *ops, jax.random.PRNGKey(0))
+        finally:
+            self.pool.release(pages)
+        return logits[0]
 
     # ------------------------------------------------------- decode hot loop
     def warmup_decode(self, max_pages: Optional[int] = None,
@@ -758,11 +796,7 @@ class FlowServe:
         max_new_tokens stop fired (post-stop tokens — sampled because EOS is
         checked one horizon late — are discarded)."""
         toks_dev, rows, k = self._inflight.popleft()
-        try:
-            ready = bool(toks_dev.is_ready())
-        except AttributeError:
-            ready = False
-        if not ready:
+        if not toks_dev.is_ready():
             self.host_syncs += 1
         toks = np.asarray(toks_dev)
         for slot, sid in rows:
@@ -1075,6 +1109,7 @@ class FlowServe:
                     f"request {req.req_id}")
             self.runner.import_kv(payload, seq)
         self.scheduler.admit_running(seq)
+        self._drop_idle_plan()
         return req.req_id
 
     # ---------------------------------------------------------------- internals

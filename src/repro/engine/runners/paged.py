@@ -535,7 +535,7 @@ class PagedDecodeRunner:
         chained call-to-call, so the warmup never touches live pages and
         peaks at one extra pool copy). Returns the number of executables
         compiled. Note: ``jit.lower().compile()`` does NOT seed the dispatch
-        cache on this jax version, so the warmup must really call."""
+        cache, so the warmup must really call."""
         rt = self.rt
         k = jnp.zeros_like(rt.pool.k)
         v = jnp.zeros_like(rt.pool.v)

@@ -12,25 +12,19 @@ from __future__ import annotations
 import jax
 
 
-def _mk(shape, axes):
-    """jax.make_mesh across jax versions: axis_types only exists on newer
-    releases (all axes are Auto there anyway, which is also the default)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh for CPU tests (requires forced host device count)."""
-    return _mk((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(_AUTO, _AUTO))
 
 
 def make_engine_mesh(tp: int, offset: int = 0):

@@ -1,5 +1,7 @@
 """Serving launcher: wire FLOWSERVE TEs + a model-serving JE + the
-autoscaler into a runnable deployment (CPU: smoke-config models).
+autoscaler into a runnable deployment. The model is the arch's smoke
+variant (CPU-sized widths) unless ``--published`` asks for its published
+widths; ``--layers`` cuts the depth either way.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \
         --mode colocated --requests 16
@@ -7,17 +9,26 @@ autoscaler into a runnable deployment (CPU: smoke-config models).
     # the live serving plane (DESIGN.md §9): Algorithm 1 over a real fleet
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \
         --topology pd=1,colo=1 --policy dist_sched --requests 16
+
+    # on a TPU: qwen3-8b at published widths, cut to 4 layers
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \
+        --published --layers 4 --topology pd=1,colo=1 --policy round_robin
+
+A run in which fewer requests complete than were submitted exits non-zero
+(``scheduled`` mode: than were placed on a TE with an engine; the plane:
+unless a fault plan is attached).
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.configs.base import get_config, smoke_config
+from repro.configs.base import get_config
 from repro.core.heatmap import HeatmapStudy
 from repro.core.predictor import (DecodeLengthPredictor, PredictorConfig,
                                   synth_trace, train_predictor)
@@ -25,7 +36,20 @@ from repro.core.scheduling import (DistributedScheduler, SchedRequest,
                                    TEHandle)
 from repro.engine import EngineConfig, FlowServe, Request, SamplingParams
 from repro.engine.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
+
+
+def build_model(arch: str, published: bool = False,
+                layers: Optional[int] = None, seed: int = 0):
+    """(bundle, params) for ``arch``: its smoke variant, or its published
+    widths with ``published``; ``layers`` cuts the depth. The float32
+    weights are random from ``seed``, made by one jitted program on the
+    default device so the stacked layers never exist twice."""
+    bundle = get_model(arch, smoke=not published, n_layers=layers)
+    params = jax.jit(lambda k: bundle.init_params(k, jnp.float32))(
+        jax.random.PRNGKey(seed))
+    return bundle, params
 
 
 def build_te(bundle, params, mode: str, name: str, tp: int = 1,
@@ -42,6 +66,11 @@ def main() -> None:
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--mode", default="colocated",
                     choices=["colocated", "pd", "scheduled"])
+    ap.add_argument("--published", action="store_true",
+                    help="build the arch at its published widths (default: "
+                         "the CPU-sized smoke variant)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to this many layers")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--tp", type=int, default=1,
@@ -76,11 +105,11 @@ def main() -> None:
                          "worker threads so engines overlap wall-clock "
                          "work; 0/1 = serial stepping")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
     if args.tp > 1:
         print(f"TE mesh: 1x{args.tp} over {jax.device_count()} visible devices")
 
-    bundle = get_model(args.arch, smoke=True)
-    params = bundle.init_params(jax.random.PRNGKey(0), jnp.float32)
+    bundle, params = build_model(args.arch, args.published, args.layers)
     tok = ByteTokenizer()
     sp = SamplingParams(temperature=0.0, max_new_tokens=args.max_new,
                         stop_on_eos=False)
@@ -142,7 +171,12 @@ def main() -> None:
                      if m["type"] == "pd_pair" else "")
             print(f"  {te_id}: type={m['type']} state={m['state']}"
                   f"{extra} load={m['load']:.1f}")
+        for f in je.unit_failures:
+            print(f"  unit failure at step {f['step']}: {f['unit']} "
+                  f"{f['type']}: {f['message']}")
         je.close()
+        if je.fault_plan is None:
+            _require_all(len(comps), len(prompts))
         return
 
     if args.mode == "colocated":
@@ -159,6 +193,7 @@ def main() -> None:
             print(f"  {c.req_id}: ttft={c.ttft*1e3:.0f}ms tpot={c.tpot*1e3:.1f}ms "
                   f"text={tok.decode(c.tokens)[:40]!r}")
         print("prefix-cache:", te.prefix_cache_stats())
+        _require_all(len(comps), len(prompts))
         return
 
     if args.mode == "pd":
@@ -180,6 +215,7 @@ def main() -> None:
             comps.extend(de.step())
         print(f"PD-disaggregated: {len(comps)} completions; "
               f"KV moved {pe.distflow.bytes_moved()/1e6:.2f} MB")
+        _require_all(len(comps), len(prompts))
         return
 
     # scheduled: JE + Algorithm 1 over 2 colocated + 1 PD pair
@@ -193,18 +229,28 @@ def main() -> None:
            TEHandle("te-pd0", "pd_pair")]
     ds = DistributedScheduler(tes, hs.combined(), hs.prefill_lens,
                               hs.decode_ratios, predictor=pred)
+    routed = 0                  # requests placed on a TE with an engine
     for p in prompts:
         toks = tok.encode(p)
         te = ds.dist_sched(SchedRequest(tokens=toks))
         ds.commit(SchedRequest(tokens=toks), te)
         if te.engine is not None:
             te.engine.add_request(Request(prompt_tokens=toks, sampling=sp))
+            routed += 1
     done = 0
     for te in tes:
         if te.engine is not None:
             done += len(te.engine.run_to_completion())
     print(f"scheduled mode: {done} completions; decisions={ds.decisions} "
           f"(predictor acc={acc:.3f})")
+    _require_all(done, routed)
+
+
+def _require_all(n_done: int, n_submitted: int) -> None:
+    if n_done < n_submitted:
+        print(f"FAILED: {n_done} of {n_submitted} requests completed",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

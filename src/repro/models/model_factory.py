@@ -1,6 +1,7 @@
 """Bundle a ModelConfig into callables the engine / launcher / tests use."""
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
@@ -51,10 +52,17 @@ def cross_entropy(logits: jax.Array, targets: jax.Array, mask: jax.Array,
     return jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def get_model(name_or_cfg, smoke: bool = False) -> ModelBundle:
+def get_model(name_or_cfg, smoke: bool = False,
+              n_layers: Optional[int] = None) -> ModelBundle:
+    """``smoke`` shrinks the widths to a CPU-sized variant; ``n_layers``
+    cuts the depth and keeps the widths (a published-width model cut to
+    what one chip holds)."""
     cfg = name_or_cfg if isinstance(name_or_cfg, ModelConfig) else get_config(name_or_cfg)
     if smoke:
         cfg = smoke_config(cfg)
+    if n_layers is not None and n_layers != cfg.n_layers:
+        cfg = dataclasses.replace(cfg, name=f"{cfg.name}-{n_layers}l",
+                                  n_layers=n_layers)
     return ModelBundle(
         cfg=cfg,
         init_params=lambda key, dtype=jnp.bfloat16: T.init_params(cfg, key, dtype),

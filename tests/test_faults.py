@@ -247,6 +247,7 @@ def _window_plane():
     je._window_of = {}
     je._window_lock = threading.Lock()
     je._reserved_windows = set()
+    je.window_fallbacks = 0
     return je
 
 
@@ -392,6 +393,28 @@ def test_live_te_kill_recovers_all_requests_with_parity(qwen, threads):
         # repair: scale_to refills the lost capacity from survivors
         plan = je.scale_to(3)
         assert je.n_serving() == 3 and plan["tiers"]["fork"] >= 1
+    finally:
+        je.close()
+
+
+@pytest.mark.slow
+@pytest.mark.fleet
+def test_injected_crash_is_recorded(qwen):
+    """Recovery hides a failure from the requests, never from the plane's
+    books: the crash is kept with its unit, type, message and traceback."""
+    bundle, params = qwen
+    fp = FaultPlan(specs=[FaultSpec("te_crash", te="te-colo1", at_step=0)])
+    je = _plane(bundle, params, TopologySpec(colo=2), policy="round_robin",
+                fault_plan=fp)
+    try:
+        rids = [je.submit(p, SP) for p in _prompts(2)]
+        assert {c.req_id for c in je.run_to_completion()} == set(rids)
+        assert fp.fired("te_crash") == 1
+        [rec] = je.unit_failures
+        assert rec["unit"] == "te-colo1"
+        assert rec["type"] == "TEFailureError"
+        assert "te-colo1" in rec["message"]
+        assert "TEFailureError" in rec["traceback"]
     finally:
         je.close()
 

@@ -18,7 +18,7 @@ from repro.engine import EngineConfig, FlowServe, Request, SamplingParams
 from repro.engine.kv_cache import pages_needed
 from repro.engine.model_runner import SequenceState
 from repro.engine.sampling import SamplingParams as SParams
-from repro.engine.sampling import sample, sample_batch
+from repro.engine.sampling import nucleus_mask, sample, sample_batch
 from repro.launch.sharding import attn_shardable
 from repro.models import get_model
 
@@ -177,3 +177,21 @@ def test_sample_batch_mixed_params_one_dispatch():
     again = np.asarray(sample_batch(logits, temps, top_ps,
                                     jax.random.PRNGKey(1), 256))
     np.testing.assert_array_equal(toks, again)
+
+
+@pytest.mark.parametrize("top_p", [0.1, 0.5, 0.9, 0.999])
+def test_nucleus_mask_matches_sorted_cumsum(top_p):
+    """The sort-free top-p cut keeps exactly the set a descending sort +
+    cumsum keeps: the highest logits until their mass reaches top_p, ties
+    at the cut included (rows 0-1 carry deliberate ties and negatives)."""
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (6, 500))) * 3.0
+    x[0, :50] = 2.5
+    x[1] = np.round(x[1])
+    s = np.sort(x, axis=-1)[:, ::-1]
+    p = np.exp(s - s[:, :1])
+    cum = np.cumsum(p / p.sum(-1, keepdims=True), axis=-1)
+    cut = s[np.arange(6), np.sum(cum < top_p, axis=-1)]
+    want = x >= cut[:, None]
+    got = np.asarray(nucleus_mask(jnp.asarray(x, jnp.float32),
+                                  jnp.full((6,), top_p, jnp.float32)))
+    np.testing.assert_array_equal(got, want)

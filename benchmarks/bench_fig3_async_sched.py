@@ -42,8 +42,7 @@ def _run(async_sched: bool, n_requests: int = 8, new_tokens: int = 48):
     toks = n_requests * new_tokens
     steps = eng.steps - steps0
     return {"tpot_ms": wall / max(steps, 1) * 1e3,
-            "tok_per_s": toks / wall,
-            "sched_crit_ms": eng.scheduler.sched_time / max(steps, 1) * 1e3}
+            "tok_per_s": toks / wall}
 
 
 def run() -> list:
@@ -59,14 +58,6 @@ def run() -> list:
                  "(~1.0 expected on 1 CPU core: planning cannot physically "
                  "overlap the model step here; the paper's 2x needs an "
                  "accelerator running concurrently with the host)"))
-    rows.append(("fig3_sched_plan_time_per_step_v1_us",
-                 v1["sched_crit_ms"] * 1e3,
-                 "sync: planning sits on the decode critical path"))
-    rows.append(("fig3_sched_plan_time_per_step_v2_us",
-                 v2["sched_crit_ms"] * 1e3,
-                 "async: same work, but prepared while the model step runs "
-                 "(plan ready at step start for 100% of steps; outputs "
-                 "bit-identical — tests/test_system.py::test_async_vs_sync)"))
     return rows
 
 

@@ -61,8 +61,7 @@ def main() -> None:
         print(f"  - {ids[c.req_id][:36]!r:40s} ttft={c.ttft * 1e3:6.0f}ms "
               f"tpot={c.tpot * 1e3:6.1f}ms gen={tok.decode(c.tokens)[:32]!r}")
     print(f"[quickstart] prefix cache: {eng.prefix_cache_stats()}")
-    print(f"[quickstart] engine steps: {eng.steps}, "
-          f"scheduler critical-path: {eng.scheduler.sched_time * 1e3:.1f}ms total")
+    print(f"[quickstart] engine steps: {eng.steps}")
 
 
 if __name__ == "__main__":

@@ -72,6 +72,7 @@ from repro.core.scheduling import (DistSchedConfig, DistributedScheduler,
                                    round_robin_scheduler)
 from repro.engine import EngineConfig, FlowServe, Request, SamplingParams
 from repro.engine.flowserve import Completion
+from repro.engine.trace import spanned
 
 _PD_GROUP_RE = re.compile(r"^(\d+)p(\d+)d$")
 _log = logging.getLogger(__name__)
@@ -358,6 +359,7 @@ class ServingJobEngine:
             self._fork_pool = None
 
     # ------------------------------------------------------------ intake
+    @spanned("je.submit")
     def submit(self, tokens, sampling: Optional[SamplingParams] = None,
                predicted_decode: Optional[int] = None,
                request: Optional[UserRequest] = None) -> str:
@@ -442,6 +444,7 @@ class ServingJobEngine:
             f"({len(serving)} serving TEs)", req_id=request.req_id)
 
     # ------------------------------------------------------------ drive
+    @spanned("je.step")
     def step(self) -> List[Completion]:
         """One JE iteration: step every live fleet unit — serially, or as
         submit/collect over the per-TE executors (``fleet_threads > 1``) so

@@ -32,6 +32,7 @@ from repro.engine.rtc import RelationalTensorCache, RTCCostModel
 from repro.engine.sampling import SamplingParams, sample_batch
 from repro.engine.scheduler import Scheduler, SchedulerConfig
 from repro.engine.tokenizer import EOS_ID, ByteTokenizer
+from repro.engine.trace import span, spanned
 from repro.models.model_factory import ModelBundle
 
 _req_ids = itertools.count()
@@ -59,6 +60,11 @@ class Completion:
     finish: float
     arrival: float
     n_prompt: int
+    # monotonic time of the first prefill dispatch that held one of the
+    # request's tokens (or, for a prompt that needed none, of its prefill
+    # being declared done): queue wait = first_dispatch - arrival, prefill
+    # = arrival + ttft - first_dispatch
+    first_dispatch: float
 
     @property
     def tpot(self) -> float:
@@ -174,6 +180,7 @@ class FlowServe:
         self._seqs: Dict[str, SequenceState] = {}
         self._requests: Dict[str, Request] = {}
         self._ttft: Dict[str, float] = {}
+        self._first_dispatch: Dict[str, float] = {}  # see Completion
         self._next_plan = None
         self._prefill_done_buffer: List[str] = []  # P-mode: ready to migrate
         self.steps = 0
@@ -181,7 +188,29 @@ class FlowServe:
         self.decode_steps = 0            # decode iterations executed (B-wide)
         self.sampler_dispatches = 0      # STANDALONE dispatches spent sampling
         self.host_dispatches = 0         # device dispatches on the decode path
-        self.host_syncs = 0              # blocking device→host fetches
+        # decode token fetches with no later decode dispatch queued behind
+        # them, so the device has nothing left to run while the host waits:
+        # each per-step (legacy or slot) fetch, and a fused block fetched
+        # with no younger block in flight (the last one of a drain). The
+        # steady-state horizon-late fetch in _decode_fused_step has the
+        # horizon just dispatched behind it and is not one. Counted from
+        # the engine's own state, never from the device's timing.
+        self.host_syncs = 0
+        # work counters, cumulative, bumped at each dispatch: decode
+        # dispatches (fused horizons and per-step decodes), decode rows
+        # summed over decode steps, and over those steps the rows' live
+        # context tokens against the KV slots the program reads (batch
+        # bucket x page bucket x page size); prefill tokens packed
+        # (extension rows included), the sum over them of position + 1
+        # (the context each attends to), and the sum over dispatches of
+        # token bucket x page bucket x page size
+        self.decode_dispatches = 0
+        self.decode_rows = 0
+        self.decode_kv_live = 0
+        self.decode_kv_slots = 0
+        self.prefill_tokens = 0
+        self.prefill_kv_live = 0
+        self.prefill_kv_slots = 0
         # prefill-side accounting (§12): dispatches counted in BOTH modes so
         # benchmarks can compare dispatches-per-prompt-token; syncs are the
         # batched path's first-token fetches (separate from decode host_syncs,
@@ -350,6 +379,7 @@ class FlowServe:
             or self.scheduler.has_work()
 
     @_executor_safe
+    @spanned("te.step")
     def step(self) -> List[Completion]:
         """One engine iteration: (maybe prepared) plan → execute → sample →
         commit → prepare next plan (async mode prepares before sampling).
@@ -360,10 +390,12 @@ class FlowServe:
         t0 = time.monotonic()
         if self.fault_plan is not None:
             self.fault_plan.on_step(self)
-        self.scheduler.resolve_prefix()
-        self.scheduler.pump_prefetch()
-        plan = self._next_plan if (self.ecfg.async_sched and self._next_plan) \
-            else self.scheduler.prepare_next()
+        with span("te.plan"):
+            self.scheduler.resolve_prefix()
+            self.scheduler.pump_prefetch()
+            plan = self._next_plan if (self.ecfg.async_sched
+                                       and self._next_plan) \
+                else self.scheduler.prepare_next()
         self._next_plan = None
         completions: List[Completion] = []
         if self._inflight and (plan.prefill or not plan.decode):
@@ -376,10 +408,11 @@ class FlowServe:
 
         # ---------------- prefill chunks
         if plan.prefill:
-            if self.family.uses_pages and self.ecfg.batched_prefill:
-                self._prefill_batched(plan.prefill)
-            else:
-                self._prefill_legacy(plan.prefill)
+            with span("te.prefill"):
+                if self.family.uses_pages and self.ecfg.batched_prefill:
+                    self._prefill_batched(plan.prefill)
+                else:
+                    self._prefill_legacy(plan.prefill)
 
         # ---------------- decode batch
         if plan.decode:
@@ -407,23 +440,54 @@ class FlowServe:
                     handle = s.extra.pop("_kv_pending", None)
                     if handle is not None:   # first decode of a migrated seq
                         self._import_layerwise(handle, s)
-                logits = self.runner.decode(live)
-                self.decode_steps += 1
+                with span("te.decode.dispatch"):
+                    logits = self.runner.decode(live)
+                self._count_decode(1, len(live),
+                                   sum(len(s.tokens) for s in live),
+                                   len(live) * max(len(s.pages) for s in live)
+                                   if self.runner_kind == "paged" else 0)
                 self.host_dispatches += 1
                 # async scheduling: the next plan depends only on counts —
                 # prepare it *before* sampling commits token values (§4.2)
                 if self.ecfg.async_sched:
-                    self._next_plan = self.scheduler.prepare_next()
+                    self._next_plan = self._plan_next()
                 self._commit_tokens(live, logits)
                 if self._hot is not None:
                     self._hot.reset()   # device rows are stale vs host now
 
         if self.ecfg.async_sched and self._next_plan is None:
-            self._next_plan = self.scheduler.prepare_next()
+            self._next_plan = self._plan_next()
         self.steps += 1
         self.step_wall += time.monotonic() - t0
         completions.extend(self._flush_completed())
         return completions
+
+    def _plan_next(self):
+        with span("te.plan"):
+            return self.scheduler.prepare_next()
+
+    def _count_decode(self, k: int, rows: int, live_ctx: int,
+                      row_pages: int) -> None:
+        """Count one decode dispatch of ``k`` steps over ``rows`` rows whose
+        live context sums to ``live_ctx`` tokens at its first step (each row
+        grows by one per step), reading ``row_pages`` pages of KV slots per
+        step (batch width x block-table width; 0 off the paged pool)."""
+        self.decode_steps += k
+        self.decode_dispatches += 1
+        self.decode_rows += k * rows
+        if row_pages:
+            self.decode_kv_live += k * live_ctx + rows * k * (k - 1) // 2
+            self.decode_kv_slots += k * row_pages * self.ecfg.page_size
+
+    def _count_prefill(self, rows, row_pages: int = 0) -> None:
+        """Count one prefill dispatch of ``rows`` — ``(start, n_tokens)``
+        each — whose query rows read ``row_pages`` pages of KV slots in all
+        (0 off the paged pool)."""
+        for start, n in rows:
+            self.prefill_tokens += n
+            if row_pages:
+                self.prefill_kv_live += n * start + n * (n + 1) // 2
+        self.prefill_kv_slots += row_pages * self.ecfg.page_size
 
     def run_to_completion(self, max_steps: int = 10000) -> List[Completion]:
         out = []
@@ -446,6 +510,8 @@ class FlowServe:
                     self._ensure_pages(seq, seq.n_cached + len(chunk))
                     self.runner.prefill_chunk(seq, chunk)
                     self.prefill_dispatches += 1
+                    self._count_prefill([(start, len(chunk))],
+                                         len(chunk) * len(seq.pages))
             else:
                 if seq.slot is None:
                     if not self.runner.alloc_slot(seq):
@@ -459,6 +525,8 @@ class FlowServe:
                 if chunk:
                     self.runner.prefill_chunk(seq, chunk)
                     self.prefill_dispatches += 1
+                    self._count_prefill([(start, len(chunk))])
+            self._first_dispatch.setdefault(seq.seq_id, time.monotonic())
             done = seq.n_cached >= len(seq.tokens) - 1
             if done:
                 self._on_prefill_done(seq)
@@ -484,6 +552,7 @@ class FlowServe:
             if not chunk:
                 # single-token prompt or fully prefix-cached: prefill is
                 # vacuously done; run the done-transition
+                self._first_dispatch.setdefault(seq.seq_id, time.monotonic())
                 done = seq.n_cached >= len(seq.tokens) - 1
                 if done:
                     self._on_prefill_done(seq)
@@ -525,16 +594,22 @@ class FlowServe:
                          chunk + ([seq.tokens[-1]] if ext else []),
                          sp.temperature if ext else 0.0,
                          sp.top_p if ext else 1.0))
+        ops = self._pack_ragged(rows, scratch)
+        t_dispatch = time.monotonic()
         _, toks_dev, self._prefill_key = self.runner.prefill_ragged(
-            *self._pack_ragged(rows, scratch), self._prefill_key)
+            *ops, self._prefill_key)
         self.prefill_dispatches += 1
+        self._count_prefill([(r[1], len(r[2])) for r in rows],
+                            ops[0].shape[0] * ops[4].shape[1])
 
         # ---- commit: lengths, extension first-tokens, queue transitions
         toks = None
         if any(ext for _, _, _, ext in packed):
-            toks = np.asarray(toks_dev)
+            with span("te.prefill.fetch"):
+                toks = np.asarray(toks_dev)
             self.prefill_syncs += 1
         for i, (seq, start, chunk, ext) in enumerate(packed):
+            self._first_dispatch.setdefault(seq.seq_id, t_dispatch)
             seq.n_cached = start + len(chunk) + (1 if ext else 0)
             if not ext:
                 done = seq.n_cached >= len(seq.tokens) - 1
@@ -551,13 +626,7 @@ class FlowServe:
             sp = self.sample_params[seq.seq_id]
             n_new = len(seq.tokens) - seq.n_prompt
             if (sp.stop_on_eos and tok == EOS_ID) or n_new >= sp.max_new_tokens:
-                req = self._requests[seq.seq_id]
-                self._completed_buf.append(Completion(
-                    req_id=seq.seq_id, tokens=seq.tokens[seq.n_prompt:],
-                    ttft=self._ttft[seq.seq_id], finish=time.monotonic(),
-                    arrival=req.arrival, n_prompt=seq.n_prompt))
-                self.scheduler.on_finished(seq)
-                self.release_request(seq.seq_id)
+                self._finish(seq)
 
     def _pack_ragged(self, rows, scratch: int):
         """Pack prefill rows — ``(pages, start, tokens, temperature,
@@ -735,15 +804,18 @@ class FlowServe:
                 handle = s.extra.pop("_kv_pending", None)
                 if handle is not None:   # first decode of a migrated seq
                     self._import_layerwise(handle, s)
-            self.host_dispatches += hot.sync(
-                [(s.seq_id, s.pages, len(s.tokens),
-                  s.tokens[-1] if s.tokens else 0,
-                  self.sample_params[s.seq_id].temperature,
-                  self.sample_params[s.seq_id].top_p) for s in live],
-                can_shrink=not self._inflight)
-            toks = self.runner.decode_fused(hot, k)
+            with span("te.decode.sync"):
+                self.host_dispatches += hot.sync(
+                    [(s.seq_id, s.pages, len(s.tokens),
+                      s.tokens[-1] if s.tokens else 0,
+                      self.sample_params[s.seq_id].temperature,
+                      self.sample_params[s.seq_id].top_p) for s in live],
+                    can_shrink=not self._inflight)
+            with span("te.decode.dispatch"):
+                toks = self.runner.decode_fused(hot, k)
             self.host_dispatches += 1
-            self.decode_steps += k
+            self._count_decode(k, len(live), sum(hlen.values()),
+                               hot.bb * hot.pb)
             for s in live:
                 self._pending[s.seq_id] = \
                     self._pending.get(s.seq_id, 0) + k
@@ -751,7 +823,7 @@ class FlowServe:
                 (toks, [(hot.slot_of[s.seq_id], s.seq_id) for s in live], k))
             # async scheduling (§4.2): the next plan needs only counts
             if self.ecfg.async_sched:
-                self._next_plan = self.scheduler.prepare_next()
+                self._next_plan = self._plan_next()
             # fetch the PREVIOUS horizon's block — computed behind the
             # dispatch above, so the copy does not stall the device
             while len(self._inflight) > 1:
@@ -777,15 +849,17 @@ class FlowServe:
                 top_ps[s.slot] = sp.top_p
             self._sp_cache = (batch_key, temps, top_ps)
         _, temps, top_ps = self._sp_cache
-        toks_dev, self._key = self.runner.decode_sample(
-            live, temps, top_ps, self._key)
-        self.decode_steps += 1
+        with span("te.decode.dispatch"):
+            toks_dev, self._key = self.runner.decode_sample(
+                live, temps, top_ps, self._key)
+        self._count_decode(1, len(live), 0, 0)
         self.host_dispatches += 1
         # async scheduling (§4.2): the next plan needs only counts — prepare
         # it before the blocking token fetch
         if self.ecfg.async_sched:
-            self._next_plan = self.scheduler.prepare_next()
-        toks = np.asarray(toks_dev)
+            self._next_plan = self._plan_next()
+        with span("te.decode.fetch"):
+            toks = np.asarray(toks_dev)
         self.host_syncs += 1
         self._commit_sampled(live, [int(toks[s.slot]) for s in live])
         return True
@@ -796,9 +870,10 @@ class FlowServe:
         max_new_tokens stop fired (post-stop tokens — sampled because EOS is
         checked one horizon late — are discarded)."""
         toks_dev, rows, k = self._inflight.popleft()
-        if not toks_dev.is_ready():
-            self.host_syncs += 1
-        toks = np.asarray(toks_dev)
+        if not self._inflight:
+            self.host_syncs += 1     # nothing dispatched behind this block
+        with span("te.decode.fetch"):
+            toks = np.asarray(toks_dev)
         for slot, sid in rows:
             seq = self._seqs.get(sid)
             if seq is None or sid not in self._pending:
@@ -819,17 +894,23 @@ class FlowServe:
                     break
             seq.n_cached = len(seq.tokens) - 1
             if stopped:
-                self._pending.pop(sid, None)
-                req = self._requests[sid]
-                self._completed_buf.append(Completion(
-                    req_id=sid, tokens=seq.tokens[seq.n_prompt:],
-                    ttft=self._ttft[sid], finish=time.monotonic(),
-                    arrival=req.arrival, n_prompt=seq.n_prompt))
-                self.scheduler.on_finished(seq)
                 # releasing pages now is safe even with a later block in
                 # flight: pool updates chain by dispatch order, and any new
                 # owner of these pages writes (and masks) before it reads
-                self.release_request(sid)
+                self._finish(seq)
+
+    def _finish(self, seq: SequenceState) -> None:
+        """Complete ``seq``: its ``Completion`` goes out with the next
+        flush, and its pages, slot and request state are released."""
+        sid = seq.seq_id
+        req = self._requests[sid]
+        self._completed_buf.append(Completion(
+            req_id=sid, tokens=seq.tokens[seq.n_prompt:],
+            ttft=self._ttft[sid], finish=time.monotonic(),
+            arrival=req.arrival, n_prompt=seq.n_prompt,
+            first_dispatch=self._first_dispatch.get(sid, req.arrival)))
+        self.scheduler.on_finished(seq)
+        self.release_request(sid)
 
     def _drain_inflight(self) -> None:
         """Commit every in-flight horizon — host state becomes
@@ -860,12 +941,13 @@ class FlowServe:
         decode starts behind the first chunk instead of the last — the
         scatter of chunk i overlaps the wire time of chunk i+1."""
         chunks = getattr(handle, "chunks", None)
-        if chunks is None:
-            self.runner.import_kv(handle.wait(), seq.pages)
-            return
-        for i in range(len(chunks)):
-            self.runner.import_kv({"chunks": [handle.wait_chunk(i)]},
-                                  seq.pages)
+        with span("distflow.transfer"):
+            if chunks is None:
+                self.runner.import_kv(handle.wait(), seq.pages)
+                return
+            for i in range(len(chunks)):
+                self.runner.import_kv({"chunks": [handle.wait_chunk(i)]},
+                                      seq.pages)
 
     # ---------------------------------------------------------------- PD
     @_executor_safe
@@ -902,6 +984,8 @@ class FlowServe:
         # a mid-decode sequence (drain migration) already produced its first
         # token here — carry the TTFT so the destination doesn't re-stamp it
         payload["ttft"] = self._ttft.get(req_id, 0.0)
+        payload["first_dispatch"] = self._first_dispatch.get(
+            req_id, payload["arrival"])
         return payload
 
     def migrate_out(self, req_id: str, dst: "FlowServe", overlap: bool = True,
@@ -954,18 +1038,21 @@ class FlowServe:
                     # device-resident path never pays
                     n_kv = _nbytes([payload["k"], payload["v"]])
                     self.distflow.charge(n_kv, "pcie_dram")
-                self.distflow.transfer(
-                    BufferInfo(owner=self.name, tier="npu", payload=payload),
-                    BufferInfo(owner=dst.name, tier="npu",
-                               deliver=dst.import_request))
+                with span("distflow.transfer"):
+                    self.distflow.transfer(
+                        BufferInfo(owner=self.name, tier="npu",
+                                   payload=payload),
+                        BufferInfo(owner=dst.name, tier="npu",
+                                   deliver=dst.import_request))
                 if host_gather and self.runner_kind == "paged":
                     dst.distflow.charge(n_kv, "pcie_dram")
             else:
                 kv = {"k": payload.pop("k"), "v": payload.pop("v")}
-                handle = self.distflow.transfer_sharded(
-                    kv, dst.name, dst_sharding=dst.pool.run_sharding(),
-                    src_tp=self.ecfg.tp, dst_tp=dst.ecfg.tp,
-                    layer_chunks=layer_chunks)
+                with span("distflow.transfer"):
+                    handle = self.distflow.transfer_sharded(
+                        kv, dst.name, dst_sharding=dst.pool.run_sharding(),
+                        src_tp=self.ecfg.tp, dst_tp=dst.ecfg.tp,
+                        layer_chunks=layer_chunks)
                 payload["kv_handle"] = handle
                 dst.import_request(payload)
                 if not overlap:
@@ -1025,6 +1112,7 @@ class FlowServe:
     def release_request(self, req_id: str, keep_prefix: bool = True) -> None:
         seq = self._seqs.pop(req_id, None)
         self._pending.pop(req_id, None)
+        self._first_dispatch.pop(req_id, None)
         if self._hot is not None:
             self._hot.evict(req_id)   # a reused id must join fresh, not alias
         if seq is None:
@@ -1061,6 +1149,8 @@ class FlowServe:
         if (payload.get("ttft", 0.0) > 0.0
                 and len(payload["tokens"]) > payload["n_prompt"]):
             self._ttft[req.req_id] = payload["ttft"]
+        if "first_dispatch" in payload:
+            self._first_dispatch[req.req_id] = payload["first_dispatch"]
         seq = SequenceState(seq_id=req.req_id,
                             tokens=list(payload["tokens"]),
                             n_prompt=payload["n_prompt"],
@@ -1184,8 +1274,9 @@ class FlowServe:
                 np.asarray([sp.temperature for sp in sps], np.float32),
                 np.asarray([sp.top_p for sp in sps], np.float32))
         _, temps, top_ps = self._sp_cache
-        toks = np.asarray(sample_batch(logits, temps, top_ps, sub,
-                                       self.cfg.vocab_size))
+        with span("te.decode.fetch"):
+            toks = np.asarray(sample_batch(logits, temps, top_ps, sub,
+                                           self.cfg.vocab_size))
         self.sampler_dispatches += 1
         self.host_dispatches += 1
         self.host_syncs += 1             # np.asarray blocks on this step
@@ -1202,13 +1293,7 @@ class FlowServe:
                 self._ttft[seq.seq_id] = time.monotonic() - self._requests[seq.seq_id].arrival
             n_new = len(seq.tokens) - seq.n_prompt
             if (sp.stop_on_eos and tok == EOS_ID) or n_new >= sp.max_new_tokens:
-                req = self._requests[seq.seq_id]
-                self._completed_buf.append(Completion(
-                    req_id=seq.seq_id, tokens=seq.tokens[seq.n_prompt:],
-                    ttft=self._ttft[seq.seq_id], finish=time.monotonic(),
-                    arrival=req.arrival, n_prompt=seq.n_prompt))
-                self.scheduler.on_finished(seq)
-                self.release_request(seq.seq_id)
+                self._finish(seq)
 
     def _try_state_reuse(self, seq: SequenceState) -> None:
         """SSM prefix cache: longest state checkpoint whose token prefix

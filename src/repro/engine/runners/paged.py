@@ -73,6 +73,20 @@ class PagedRunner:
         self.prefill = PagedPrefillRunner(self)
         self.decoder = PagedDecodeRunner(self)
 
+    def _mlp(self, p, x):
+        """One block's feed-forward half (norm, dense or MoE MLP, optional
+        post-norm), the residual left to the caller."""
+        cfg = self.cfg
+        h = L.apply_norm(x, p["ln2"], cfg.norm)
+        if "moe" in p:
+            from repro.models import moe as M
+            m = M.moe_apply(p["moe"], h, cfg.moe, cfg.mlp_act, groups=1)
+        else:
+            m = L.mlp_apply(p["mlp"], h, cfg.mlp_act)
+        if cfg.post_norms:
+            m = L.apply_norm(m, p["ln2_post"], cfg.norm)
+        return m
+
     def _jit_step(self, fn, donate: Tuple[int, ...]):
         """jit with TP shardings pinned when the runner spans a mesh:
         weights keep their placement, token/page operands replicate, and the
@@ -217,15 +231,7 @@ class PagedPrefillRunner:
                 mask &= kpos[:, None, :] > (positions[:, :, None] - wins[li])
                 o = L.attention(q, k_seq, v_seq, mask, cfg.attn_logit_softcap)
                 x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o))
-                h = L.apply_norm(x, p["ln2"], cfg.norm)
-                if "moe" in p:
-                    from repro.models import moe as M
-                    m = M.moe_apply(p["moe"], h, cfg.moe, cfg.mlp_act, groups=1)
-                else:
-                    m = L.mlp_apply(p["mlp"], h, cfg.mlp_act)
-                if cfg.post_norms:
-                    m = L.apply_norm(m, p["ln2_post"], cfg.norm)
-                x = x + m
+                x = x + rt._mlp(p, x)
             logits = T.unembed(cfg, params, x[:, -1:])[:, 0]
             return logits, k_pool, v_pool
 
@@ -294,44 +300,42 @@ class PagedPrefillRunner:
                              T.GLOBAL_WINDOW + 1)               # (Tb,total)
             for li in range(cfg.n_layers):
                 p = jax.tree.map(lambda a: a[li], params["blocks"])
-                h = L.apply_norm(x, p["ln1"], cfg.norm)
-                q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads,
-                                             cfg.n_kv_heads, cfg.head_dim,
-                                             pos2, cfg.rope_theta,
-                                             cfg.qk_norm)
+                with jax.named_scope("attention"):
+                    h = L.apply_norm(x, p["ln1"], cfg.norm)
+                    q, k_new, v_new = L.attn_qkv(
+                        p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
+                        cfg.head_dim, pos2, cfg.rope_theta, cfg.qk_norm)
                 # ONE scatter of the whole step's fresh KV, all sequences at
                 # once; chunk-internal attention works because the scatter
                 # precedes the gather within the layer.
-                k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
-                v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
-                k_seq = k_pool[li, bt_tok].reshape(tb, total, cfg.n_kv_heads,
-                                                   cfg.head_dim)
-                v_seq = v_pool[li, bt_tok].reshape(tb, total, cfg.n_kv_heads,
-                                                   cfg.head_dim)
-                mask = L.causal_mask(pos2, kpos)
-                mask &= kpos[:, None, :] > (pos2[:, :, None] - wins[li])
-                o = L.attention(q, k_seq, v_seq, mask, cfg.attn_logit_softcap)
-                x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o))
-                h = L.apply_norm(x, p["ln2"], cfg.norm)
-                if "moe" in p:
-                    from repro.models import moe as M
-                    m = M.moe_apply(p["moe"], h, cfg.moe, cfg.mlp_act,
-                                    groups=1)
-                else:
-                    m = L.mlp_apply(p["mlp"], h, cfg.mlp_act)
-                if cfg.post_norms:
-                    m = L.apply_norm(m, p["ln2_post"], cfg.norm)
-                x = x + m
+                with jax.named_scope("kv_scatter"):
+                    k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
+                    v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
+                with jax.named_scope("kv_gather"):
+                    k_seq = k_pool[li, bt_tok].reshape(
+                        tb, total, cfg.n_kv_heads, cfg.head_dim)
+                    v_seq = v_pool[li, bt_tok].reshape(
+                        tb, total, cfg.n_kv_heads, cfg.head_dim)
+                with jax.named_scope("attention"):
+                    mask = L.causal_mask(pos2, kpos)
+                    mask &= kpos[:, None, :] > (pos2[:, :, None] - wins[li])
+                    o = L.attention(q, k_seq, v_seq, mask,
+                                    cfg.attn_logit_softcap)
+                    x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o))
+                with jax.named_scope("mlp"):
+                    x = x + rt._mlp(p, x)
             # unembed ONLY the chunk-final rows — (Sb, Vp), not (Tb, Vp)
-            logits = T.unembed(cfg, params, x[final_idx])[:, 0]
-            key, sub = jax.random.split(key)
-            all_greedy = jnp.all(temps <= 0.0)
-            toks = jax.lax.cond(
-                all_greedy,
-                lambda lg: greedy_core(lg, cfg.vocab_size),
-                lambda lg: sample_core(lg, temps, top_ps, sub,
-                                       cfg.vocab_size),
-                logits)
+            with jax.named_scope("lm_head"):
+                logits = T.unembed(cfg, params, x[final_idx])[:, 0]
+            with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
+                all_greedy = jnp.all(temps <= 0.0)
+                toks = jax.lax.cond(
+                    all_greedy,
+                    lambda lg: greedy_core(lg, cfg.vocab_size),
+                    lambda lg: sample_core(lg, temps, top_ps, sub,
+                                           cfg.vocab_size),
+                    logits)
             return logits, toks, key, k_pool, v_pool
 
         if rt.mesh is None:
@@ -423,28 +427,27 @@ class PagedDecodeRunner:
         slot = (lengths - 1) % ps
         for li in range(cfg.n_layers):
             p = jax.tree.map(lambda a: a[li], params["blocks"])
-            h = L.apply_norm(x, p["ln1"], cfg.norm)
-            q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads,
-                                         cfg.n_kv_heads, cfg.head_dim,
-                                         pos, cfg.rope_theta, cfg.qk_norm)
-            k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
-            v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
+            with jax.named_scope("attention"):
+                h = L.apply_norm(x, p["ln1"], cfg.norm)
+                q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads,
+                                             cfg.n_kv_heads, cfg.head_dim,
+                                             pos, cfg.rope_theta, cfg.qk_norm)
+            with jax.named_scope("kv_scatter"):
+                k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
+                v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
             win = wins[li] if wins[li] < T.GLOBAL_WINDOW else None
-            o = KREF.paged_attention_ref(q[:, 0], k_pool[li], v_pool[li],
-                                         bt, lengths,
-                                         softcap=cfg.attn_logit_softcap,
-                                         window=win)
-            x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o[:, None]))
-            h = L.apply_norm(x, p["ln2"], cfg.norm)
-            if "moe" in p:
-                from repro.models import moe as M
-                m = M.moe_apply(p["moe"], h, cfg.moe, cfg.mlp_act, groups=1)
-            else:
-                m = L.mlp_apply(p["mlp"], h, cfg.mlp_act)
-            if cfg.post_norms:
-                m = L.apply_norm(m, p["ln2_post"], cfg.norm)
-            x = x + m
-        logits = T.unembed(cfg, params, x)[:, 0]
+            # the page-run gather inside is scoped kv_gather by the kernel
+            with jax.named_scope("attention"):
+                o = KREF.paged_attention_ref(q[:, 0], k_pool[li], v_pool[li],
+                                             bt, lengths,
+                                             softcap=cfg.attn_logit_softcap,
+                                             window=win)
+                x = x + S._post_attn(cfg, p,
+                                     L.attn_out(p["attn"], o[:, None]))
+            with jax.named_scope("mlp"):
+                x = x + rt._mlp(p, x)
+        with jax.named_scope("lm_head"):
+            logits = T.unembed(cfg, params, x)[:, 0]
         return logits, k_pool, v_pool
 
     def _decode_fn(self, maxp: int):
@@ -500,16 +503,17 @@ class PagedDecodeRunner:
                 key, last_tok, lengths, k_pool, v_pool = carry
                 logits, k_pool, v_pool = self._decode_body(
                     params, last_tok, bt, lengths, k_pool, v_pool)
-                key, sub = jax.random.split(key)
-                toks = jax.lax.cond(
-                    all_greedy,
-                    lambda lg: greedy_core(lg, cfg.vocab_size),
-                    lambda lg: sample_core(lg, temps, top_ps, sub,
-                                           cfg.vocab_size),
-                    logits)
-                # padding rows: freeze token + length so their KV write stays
-                # parked at slot 0 of the pool's scratch page forever
-                toks = jnp.where(active, toks, last_tok)
+                with jax.named_scope("sample"):
+                    key, sub = jax.random.split(key)
+                    toks = jax.lax.cond(
+                        all_greedy,
+                        lambda lg: greedy_core(lg, cfg.vocab_size),
+                        lambda lg: sample_core(lg, temps, top_ps, sub,
+                                               cfg.vocab_size),
+                        logits)
+                    # padding rows: freeze token + length so their KV write
+                    # stays parked at slot 0 of the pool's scratch page
+                    toks = jnp.where(active, toks, last_tok)
                 return (key, toks, lengths + act, k_pool, v_pool), toks
 
             (key, last_tok, lengths, k_pool, v_pool), toks = jax.lax.scan(

@@ -9,12 +9,11 @@ mechanisms:
     (pumped off the critical path), then join the ready queue;
   * async (zero-overhead) execution — scheduling the next step needs only
     token *counts*, never token values, so ``prepare_next`` can run while
-    the model executes the current step; the engine measures the critical
-    path both ways (Figure 3's v1→v2 gap).
+    the model executes the current step; the engine's ``te.plan`` spans
+    (engine/trace.py) time it either way (Figure 3's v1→v2 gap).
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -57,7 +56,6 @@ class Scheduler:
         self.ready: deque = deque()             # prefix resolved, needs prefill
         self.prefilling: List[SequenceState] = []
         self.running: List[SequenceState] = []  # decoding
-        self.sched_time = 0.0                   # cumulative scheduler seconds
 
     # ------------------------------------------------------------ intake
     def admit(self, seq: SequenceState) -> None:
@@ -115,7 +113,6 @@ class Scheduler:
         """Build the next step's plan from queue *counts* only (async-safe).
         Chunked prefill: decode seqs cost 1 token each; the remaining token
         budget goes to prefill chunks."""
-        t0 = time.monotonic()
         plan = StepPlan()
         if self.cfg.mode != "prefill":
             plan.decode = list(self.running[: self.cfg.max_decode_batch])
@@ -148,7 +145,6 @@ class Scheduler:
                 if seq not in self.prefilling:
                     self.prefilling.append(seq)
                 budget -= take
-        self.sched_time += time.monotonic() - t0
         return plan
 
     def safe_horizon(self, batch: List[SequenceState], k_target: int,

@@ -30,8 +30,9 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     g = h // hkv
     scale = 1.0 / math.sqrt(hd)
 
-    k = k_pages[block_tables].reshape(b, maxp * p, hkv, hd)      # (B, L, Hkv, hd)
-    v = v_pages[block_tables].reshape(b, maxp * p, hkv, hd)
+    with jax.named_scope("kv_gather"):
+        k = k_pages[block_tables].reshape(b, maxp * p, hkv, hd)  # (B, L, Hkv, hd)
+        v = v_pages[block_tables].reshape(b, maxp * p, hkv, hd)
     pos = jnp.arange(maxp * p, dtype=jnp.int32)[None, :]
     valid = pos < lengths[:, None]
     if window is not None:

@@ -481,8 +481,8 @@ class FlowServe:
 
     def _count_prefill(self, rows, row_pages: int = 0) -> None:
         """Count one prefill dispatch of ``rows`` — ``(start, n_tokens)``
-        each — whose query rows read ``row_pages`` pages of KV slots in all
-        (0 off the paged pool)."""
+        each — whose queries score ``row_pages`` pages of key slots in all,
+        summed over the queries (0 off the paged pool)."""
         for start, n in rows:
             self.prefill_tokens += n
             if row_pages:
@@ -599,8 +599,9 @@ class FlowServe:
         _, toks_dev, self._prefill_key = self.runner.prefill_ragged(
             *ops, self._prefill_key)
         self.prefill_dispatches += 1
+        # every query scores every entry's gathered run: Tb x Sb x Pb pages
         self._count_prefill([(r[1], len(r[2])) for r in rows],
-                            ops[0].shape[0] * ops[4].shape[1])
+                            ops[0].shape[0] * ops[5].size)
 
         # ---- commit: lengths, extension first-tokens, queue transitions
         toks = None
@@ -632,39 +633,41 @@ class FlowServe:
         """Pack prefill rows — ``(pages, start, tokens, temperature,
         top_p)`` each — into the padded pow2-bucketed operands of
         ``runner.prefill_ragged`` (host-side, numpy): the flat token stream
-        with per-token (position, page, slot), per-token block-table rows,
-        each row's final flat index, and per-row sampling params. Padding
-        tokens park on the ``scratch`` page at position 0."""
+        with per-token (position, page, slot, entry), one block-table row
+        per entry, each entry's final flat index, and per-entry sampling
+        params. Padding tokens park on the ``scratch`` page at position 0
+        under entry index Sb, which no entry has."""
         ps = self.ecfg.page_size
         sb = pow2_bucket(max(self.ecfg.max_prefill_seqs, len(rows)))
         pb = pow2_bucket(max(len(pages) for pages, *_ in rows))
-        flat_t, flat_p, flat_pg, flat_sl, bt_tok = [], [], [], [], []
+        n = sum(len(toks) for _, _, toks, _, _ in rows)
+        tb = pow2_bucket(n)
+        flat_t = np.zeros((tb,), np.int32)
+        flat_p = np.zeros((tb,), np.int32)
+        flat_pg = np.full((tb,), scratch, np.int32)
+        flat_sl = np.zeros((tb,), np.int32)
+        seg = np.full((tb,), sb, np.int32)
+        bt_seq = np.full((sb, pb), scratch, np.int32)
         final_idx = np.zeros((sb,), np.int32)
         temps = np.zeros((sb,), np.float32)
         top_ps = np.ones((sb,), np.float32)
+        t0 = 0
         for i, (pages, start, toks, temp, top_p) in enumerate(rows):
-            row = pages + [scratch] * (pb - len(pages))
-            for j, t in enumerate(toks):
-                pos = start + j
-                flat_t.append(t)
-                flat_p.append(pos)
-                flat_pg.append(pages[pos // ps])
-                flat_sl.append(pos % ps)
-                bt_tok.append(row)
-            final_idx[i] = len(flat_t) - 1
+            t1 = t0 + len(toks)
+            pos = np.arange(start, start + len(toks), dtype=np.int32)
+            flat_t[t0:t1] = toks
+            flat_p[t0:t1] = pos
+            flat_pg[t0:t1] = np.asarray(pages, np.int32)[pos // ps]
+            flat_sl[t0:t1] = pos % ps
+            seg[t0:t1] = i
+            bt_seq[i, :len(pages)] = pages
+            final_idx[i] = t1 - 1
             temps[i] = temp
             top_ps[i] = top_p
-        tb = pow2_bucket(len(flat_t))
-        pad = tb - len(flat_t)
-        flat_t += [0] * pad
-        flat_p += [0] * pad
-        flat_pg += [scratch] * pad
-        flat_sl += [0] * pad
-        bt_tok += [[scratch] * pb] * pad
-        return (*(jnp.asarray(np.asarray(a, np.int32))
-                  for a in (flat_t, flat_p, flat_pg, flat_sl, bt_tok)),
-                jnp.asarray(final_idx), jnp.asarray(temps),
-                jnp.asarray(top_ps))
+            t0 = t1
+        return tuple(jnp.asarray(a) for a in (
+            flat_t, flat_p, flat_pg, flat_sl, seg, bt_seq, final_idx, temps,
+            top_ps))
 
     @_executor_safe
     def prompt_logits(self, tokens: List[int]) -> jax.Array:

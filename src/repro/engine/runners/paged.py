@@ -10,9 +10,11 @@ counters) and delegates to the two phase microkernels (DESIGN.md §12):
       - ``prefill_ragged``: the WHOLE step's prefill plan — every
         sequence's chunk, ragged lengths and all — packed into ONE padded
         pow2-bucketed dispatch. Flat token stream with per-token
-        (page, slot, position) indices, one KV scatter per layer across
-        all sequences, per-token block-table rows for the gather, logits
-        taken only at chunk-final rows, and first-token sampling fused in
+        (page, slot, position, entry) indices, one KV scatter per layer
+        across all sequences, one gather per layer of each entry's page
+        run (not one per token), the packed queries attending over all
+        entries' keys under a segment mask, logits taken only at
+        chunk-final rows, and first-token sampling fused in
         (``sample_core`` under a ``lax.cond`` all-greedy shortcut) so a
         completing prompt leaves the dispatch with its first token.
       - ``prefill_chunk``: the legacy batch-1 per-sequence path, kept
@@ -240,7 +242,7 @@ class PagedPrefillRunner:
         return run
 
     # ------------------------------------------------- batched ragged
-    def prefill_ragged(self, tokens, positions, pages, slots, bt_tok,
+    def prefill_ragged(self, tokens, positions, pages, slots, seg, bt_seq,
                        final_idx, temps, top_ps, key):
         """ONE dispatch for the whole step's prefill plan (DESIGN.md §12).
 
@@ -249,12 +251,11 @@ class PagedPrefillRunner:
                                                  padding tokens point at the
                                                  pool's scratch page, slot 0,
                                                  position 0
-          bt_tok                        (Tb, Pb) per-TOKEN block-table row
-                                                 (its sequence's pages,
-                                                 scratch-padded) — keying on
-                                                 the per-token table keeps
-                                                 the jit key free of the
-                                                 batch composition
+          seg                           (Tb,)    entry index of each token;
+                                                 Sb (no entry) for padding
+          bt_seq                        (Sb, Pb) each entry's page run,
+                                                 scratch-padded; padding
+                                                 entries are all scratch
           final_idx                     (Sb,)    flat index of each entry's
                                                  chunk-final token
           temps/top_ps                  (Sb,)    per-entry sampling params
@@ -263,12 +264,11 @@ class PagedPrefillRunner:
         place (donated)."""
         rt = self.rt
         tb = int(tokens.shape[0])
-        pb = int(bt_tok.shape[1])
-        sb = int(final_idx.shape[0])
+        sb, pb = (int(d) for d in bt_seq.shape)
         fn = self._ragged_fn(tb, pb, sb)
         logits, toks, key, rt.pool.k, rt.pool.v = fn(
-            rt.params, tokens, positions, pages, slots, bt_tok, final_idx,
-            temps, top_ps, key, rt.pool.k, rt.pool.v)
+            rt.params, tokens, positions, pages, slots, seg, bt_seq,
+            final_idx, temps, top_ps, key, rt.pool.k, rt.pool.v)
         return logits, toks, key
 
     def _ragged_fn(self, tb: int, pb: int, sb: int):
@@ -284,20 +284,36 @@ class PagedPrefillRunner:
         total = pb * ps
         from repro.engine.sampling import greedy_core, sample_core
 
-        def run(params, tokens, positions, page, slot, bt_tok, final_idx,
-                temps, top_ps, key, k_pool, v_pool):
-            # every packed token is its own batch row (Tb, 1, D): queries are
-            # per-token, keys are the token's own page run gathered via its
-            # block-table row — sequences never see each other's pages.
-            x = T.embed(cfg, params, tokens[:, None])           # (Tb,1,D)
-            pos2 = positions[:, None]                           # (Tb,1)
-            kpos_base = jnp.arange(total, dtype=jnp.int32)[None]
-            # slot j of a gathered run holds its sequence's token j; slots
-            # past the token's own position are either unwritten or another
-            # step's future — one causal mask covers both. Padding rows
-            # (position 0) attend only to their scratch slot.
-            kpos = jnp.where(kpos_base <= pos2, kpos_base,
-                             T.GLOBAL_WINDOW + 1)               # (Tb,total)
+        def entry_runs(pool, li, run_rows):
+            # each entry's pages, side by side on one key axis
+            # (1, Sb·total, Hkv, hd): one gather of token rows from the pool
+            # seen as (layers·pages·ps, Hkv, hd); its size does not grow
+            # with Sb or Pb. Gathering whole 512-token pages
+            # (pool[li, bt_seq]) compiles on TPU as a split of the WHOLE
+            # pool, every layer's pages, on every layer.
+            rows = pool.reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+            return rows[li * pool.shape[1] * ps + run_rows][None]
+
+        def run(params, tokens, positions, page, slot, seg, bt_seq,
+                final_idx, temps, top_ps, key, k_pool, v_pool):
+            # the packed tokens are one batch row (1, Tb, D) of queries; the
+            # keys are every entry's page run gathered once, side by side on
+            # one axis of Sb·total keys — key j is entry j // total's token
+            # j % total. A token attends only inside its own entry, so
+            # sequences never see each other's pages.
+            x = T.embed(cfg, params, tokens[None])              # (1,Tb,D)
+            pos2 = positions[None]                              # (1,Tb)
+            kj = jnp.arange(sb * total, dtype=jnp.int32)
+            kpos = kj % total
+            # slot j of a gathered run holds its entry's token j; slots past
+            # the token's own position are either unwritten or another
+            # step's future — one causal mask covers both. Padding tokens
+            # (entry Sb) match no key and leave with a uniform, finite mix.
+            own = ((seg[:, None] == (kj // total)[None])
+                   & (kpos[None] <= positions[:, None]))[None]  # (1,Tb,K)
+            # key j's row in one layer of the pool
+            run_rows = (bt_seq[:, :, None] * ps
+                        + jnp.arange(ps, dtype=jnp.int32)).reshape(-1)
             for li in range(cfg.n_layers):
                 p = jax.tree.map(lambda a: a[li], params["blocks"])
                 with jax.named_scope("attention"):
@@ -309,16 +325,14 @@ class PagedPrefillRunner:
                 # once; chunk-internal attention works because the scatter
                 # precedes the gather within the layer.
                 with jax.named_scope("kv_scatter"):
-                    k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
-                    v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
+                    k_pool = k_pool.at[li, page, slot].set(k_new[0])
+                    v_pool = v_pool.at[li, page, slot].set(v_new[0])
                 with jax.named_scope("kv_gather"):
-                    k_seq = k_pool[li, bt_tok].reshape(
-                        tb, total, cfg.n_kv_heads, cfg.head_dim)
-                    v_seq = v_pool[li, bt_tok].reshape(
-                        tb, total, cfg.n_kv_heads, cfg.head_dim)
+                    k_seq = entry_runs(k_pool, li, run_rows)
+                    v_seq = entry_runs(v_pool, li, run_rows)
                 with jax.named_scope("attention"):
-                    mask = L.causal_mask(pos2, kpos)
-                    mask &= kpos[:, None, :] > (pos2[:, :, None] - wins[li])
+                    mask = own & (kpos[None, None]
+                                  > (pos2[:, :, None] - wins[li]))
                     o = L.attention(q, k_seq, v_seq, mask,
                                     cfg.attn_logit_softcap)
                     x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o))
@@ -326,7 +340,7 @@ class PagedPrefillRunner:
                     x = x + rt._mlp(p, x)
             # unembed ONLY the chunk-final rows — (Sb, Vp), not (Tb, Vp)
             with jax.named_scope("lm_head"):
-                logits = T.unembed(cfg, params, x[final_idx])[:, 0]
+                logits = T.unembed(cfg, params, x[:, final_idx])[0]
             with jax.named_scope("sample"):
                 key, sub = jax.random.split(key)
                 all_greedy = jnp.all(temps <= 0.0)
@@ -339,12 +353,12 @@ class PagedPrefillRunner:
             return logits, toks, key, k_pool, v_pool
 
         if rt.mesh is None:
-            fn = jax.jit(run, donate_argnums=(10, 11))
+            fn = jax.jit(run, donate_argnums=(11, 12))
         else:
             r, kv = rt._repl, rt._kv_sh
-            fn = jax.jit(run, donate_argnums=(10, 11),
+            fn = jax.jit(run, donate_argnums=(11, 12),
                          in_shardings=(rt._param_sh, r, r, r, r, r, r, r, r,
-                                       r, kv, kv),
+                                       r, r, kv, kv),
                          out_shardings=(r, r, r, kv, kv))
         self._ragged_fns[key_t] = fn
         return fn
@@ -370,7 +384,8 @@ class PagedPrefillRunner:
                     rt.params, jnp.zeros((tb,), jnp.int32),
                     jnp.zeros((tb,), jnp.int32), jnp.zeros((tb,), jnp.int32),
                     jnp.zeros((tb,), jnp.int32),
-                    jnp.zeros((tb, pb), jnp.int32),
+                    jnp.zeros((tb,), jnp.int32),
+                    jnp.zeros((n_rows, pb), jnp.int32),
                     jnp.zeros((n_rows,), jnp.int32),
                     jnp.zeros((n_rows,), jnp.float32),
                     jnp.ones((n_rows,), jnp.float32), key, k, v)

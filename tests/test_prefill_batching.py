@@ -19,6 +19,7 @@ import pytest
 from repro.engine import EngineConfig, FlowServe, Request, SamplingParams
 from repro.engine.runners import (RunnerFamily, families, pick_runner,
                                   register_family, resolve_family)
+from repro.engine.runners.base import SequenceState
 from repro.models import get_model
 
 needs2 = pytest.mark.skipif(
@@ -156,6 +157,101 @@ def test_max_new_tokens_one_finishes_in_prefill(qwen):
     got, te = _serve(qwen, _prompts(2), sp=sp, batched_prefill=True)
     assert got == want
     assert te.decode_steps == 0
+
+
+# ---------------------------------------------------------------------------
+# One dispatch vs per-sequence chunks: logits and written KV, per mechanism
+# ---------------------------------------------------------------------------
+
+# one step's plan at page size 8: (cached prefix, chunk length, extension
+# row). A fresh entry; an entry past the smoke window (16) whose chunk ends
+# its prompt, so the last prompt token rides along; a short mid-prompt one.
+# 5 + 7 + 2 = 14 tokens in a bucket of 16, 3 entries in a bucket of 4.
+PLAN = [(0, 5, False), (16, 6, True), (3, 2, False)]
+PS = 8
+
+
+def _planned_engine(bundle, params):
+    """An engine whose pool holds the PLAN's cached prefixes, written by
+    the per-sequence path, and the plan's rows ``(pages, start, tokens)``."""
+    te = FlowServe(bundle, params, EngineConfig(
+        n_pages=32, page_size=PS, max_prefill_seqs=4))
+    scratch = te.pool.scratch_page()
+    rows = []
+    for i, (start, n, ext) in enumerate(PLAN):
+        toks = [int(t) for t in np.random.RandomState(10 + i).randint(
+            3, 200, start + n + ext)]
+        pages = te.pool.alloc(-(-len(toks) // PS))
+        if start:
+            te.runner.prefill_chunk(SequenceState(
+                f"s{i}", toks[:start], start, pages=pages), toks[:start])
+        rows.append((pages, start, toks[start:]))
+    return te, scratch, rows
+
+
+@pytest.mark.parametrize("arch", ["qwen3-8b", "gemma2-9b", "mixtral-8x7b"])
+def test_ragged_dispatch_matches_per_sequence_chunks(arch):
+    """qwen3 (GQA, qk-norm), gemma2 (local/global window, softcap) and
+    mixtral (sliding window, MoE): one ragged dispatch of the PLAN, padding
+    tokens and a padding entry included, gives each entry the chunk-final
+    logits and writes into its pages the KV that a per-sequence
+    ``prefill_chunk`` of the same chunk does."""
+    bundle = get_model(arch, smoke=True)
+    params = bundle.init_params(jax.random.PRNGKey(0), jnp.float32)
+    te, scratch, rows = _planned_engine(bundle, params)
+    ops = te._pack_ragged([(pg, st, tk, 0.0, 1.0) for pg, st, tk in rows],
+                          scratch)
+    assert ops[0].shape == (16,) and ops[5].shape == (4, 4)
+    got, _, _ = te.runner.prefill_ragged(*ops, jax.random.PRNGKey(0))
+    ref, _, ref_rows = _planned_engine(bundle, params)
+    for i, (pages, start, toks) in enumerate(ref_rows):
+        seq = SequenceState(f"s{i}", toks, start + len(toks), n_cached=start,
+                            pages=pages)
+        want = ref.runner.prefill_chunk(seq, toks)
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+        pos = np.arange(start + len(toks))
+        page, slot = np.asarray(pages)[pos // PS], pos % PS
+        for pool_got, pool_want in ((te.pool.k, ref.pool.k),
+                                    (te.pool.v, ref.pool.v)):
+            np.testing.assert_allclose(
+                np.asarray(pool_got[:, page, slot]),
+                np.asarray(pool_want[:, page, slot]), rtol=1e-4, atol=1e-4)
+
+
+def _avals(jaxpr):
+    """Every intermediate's shape in ``jaxpr`` and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(v.aval.shape)
+        for prm in eqn.params.values():
+            for sub in (prm if isinstance(prm, (list, tuple)) else (prm,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _avals(sub)
+
+
+def test_ragged_dispatch_gathers_no_per_token_run(qwen):
+    """At the bucket (Tb 128, Pb 8, Sb 4) no intermediate of the traced
+    dispatch holds a page run per packed token — (Tb, Pb·ps, Hkv, hd) or
+    its unreshaped (Tb, Pb, ps, Hkv, hd) — while the entries' runs side by
+    side, (1, Sb·Pb·ps, Hkv, hd), are there: each run is gathered once."""
+    bundle, params = qwen
+    cfg = bundle.cfg
+    te = FlowServe(bundle, params, EngineConfig(n_pages=16, page_size=8))
+    tb, pb, sb, ps = 128, 8, 4, 8
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)      # noqa: E731
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)    # noqa: E731
+    pool = jax.ShapeDtypeStruct(te.pool.k.shape, te.pool.k.dtype)
+    fn = te.runner.prefill._ragged_fn(tb, pb, sb)
+    jaxpr = jax.make_jaxpr(fn)(
+        params, i32(tb), i32(tb), i32(tb), i32(tb), i32(tb), i32(sb, pb),
+        i32(sb), f32(sb), f32(sb), jax.random.PRNGKey(0), pool, pool)
+    shapes = set(_avals(jaxpr.jaxpr))
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    assert (tb, pb * ps, hkv, hd) not in shapes
+    assert (tb, pb, ps, hkv, hd) not in shapes
+    assert (1, sb * pb * ps, hkv, hd) in shapes
 
 
 # ---------------------------------------------------------------------------

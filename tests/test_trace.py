@@ -8,8 +8,9 @@ tokens each, greedy.
 - Prefill, step 1: 8 tokens of each prompt (positions 0-7, one page each),
   packed into a token bucket of 32 x page bucket 1. Step 2: positions 8-10
   and the extension row at 11 (two pages), bucket 16 x 2. Tokens 24 + 12;
-  live context sum over tokens of position + 1 = 3 x 78 = 234; slots
-  (32 x 1 + 16 x 2) x 8 = 512.
+  live context sum over tokens of position + 1 = 3 x 78 = 234. Every
+  query scores every entry's gathered run, and the entry bucket is 8
+  (``max_prefill_seqs``): slots (32 x 8 x 1 + 16 x 8 x 2) x 8 = 4096.
 - Decode: the first token came from prefill, so each request owes 9. The
   horizons are 4 (contexts 13-16), 4 (17-20) and 1 (21), all at batch
   bucket 4 x page bucket 4 (21 tokens need 3 pages): 3 dispatches, 9
@@ -57,7 +58,7 @@ def test_counters_are_exact_on_a_fixed_schedule(qwen):
         "prefill_kv_slots", "decode_dispatches", "decode_steps",
         "decode_rows", "decode_kv_live", "decode_kv_slots", "host_syncs")}
     assert got == {"prefill_dispatches": 2, "prefill_tokens": 36,
-                   "prefill_kv_live": 234, "prefill_kv_slots": 512,
+                   "prefill_kv_live": 234, "prefill_kv_slots": 4096,
                    "decode_dispatches": 3, "decode_steps": 9,
                    "decode_rows": 27, "decode_kv_live": 459,
                    "decode_kv_slots": 1152, "host_syncs": 1}

@@ -90,6 +90,9 @@ class ModelConfig:
     final_logit_softcap: Optional[float] = None
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # share of each head's channels that RoPE rotates (the leading ones);
+    # the rest pass through unrotated
+    partial_rotary_factor: float = 1.0
 
     # --- MLP flavour ---
     mlp_act: str = "swiglu"         # swiglu | geglu | sqrelu
@@ -119,6 +122,15 @@ class ModelConfig:
         mesh-shardable-bounded) attention state: SSM / hybrid / SWA /
         alternating local-global."""
         return self.attn_kind in ("rwkv", "hybrid_rglru", "swa", "local_global")
+
+    @property
+    def rotary_dim(self) -> int:
+        """Channels of each head that RoPE rotates: head_dim at factor 1.0."""
+        rd = int(self.head_dim * self.partial_rotary_factor)
+        if rd % 2 or not 0 < rd <= self.head_dim:
+            raise ValueError(f"rotary width {rd} of head_dim {self.head_dim} "
+                             f"must be even and positive")
+        return rd
 
     @property
     def is_encdec(self) -> bool:

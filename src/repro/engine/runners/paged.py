@@ -222,7 +222,8 @@ class PagedPrefillRunner:
                 h = L.apply_norm(x, p["ln1"], cfg.norm)
                 q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads,
                                              cfg.n_kv_heads, cfg.head_dim,
-                                             positions, cfg.rope_theta, cfg.qk_norm)
+                                             positions, cfg.rope_theta, cfg.qk_norm,
+                                             rotary_dim=cfg.rotary_dim)
                 k_pool = k_pool.at[li, page, slot].set(k_new[0])
                 v_pool = v_pool.at[li, page, slot].set(v_new[0])
                 k_seq = k_pool[li, bt[0]].reshape(1, total, cfg.n_kv_heads, cfg.head_dim)
@@ -320,7 +321,8 @@ class PagedPrefillRunner:
                     h = L.apply_norm(x, p["ln1"], cfg.norm)
                     q, k_new, v_new = L.attn_qkv(
                         p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
-                        cfg.head_dim, pos2, cfg.rope_theta, cfg.qk_norm)
+                        cfg.head_dim, pos2, cfg.rope_theta, cfg.qk_norm,
+                        rotary_dim=cfg.rotary_dim)
                 # ONE scatter of the whole step's fresh KV, all sequences at
                 # once; chunk-internal attention works because the scatter
                 # precedes the gather within the layer.
@@ -446,7 +448,8 @@ class PagedDecodeRunner:
                 h = L.apply_norm(x, p["ln1"], cfg.norm)
                 q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads,
                                              cfg.n_kv_heads, cfg.head_dim,
-                                             pos, cfg.rope_theta, cfg.qk_norm)
+                                             pos, cfg.rope_theta, cfg.qk_norm,
+                                             rotary_dim=cfg.rotary_dim)
             with jax.named_scope("kv_scatter"):
                 k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
                 v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])

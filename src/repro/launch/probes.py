@@ -250,7 +250,8 @@ def _encoder_probe(cfg, shape, params_like, dp, dtype, mode) -> Probe:
         pos = jnp.broadcast_to(jnp.arange(f_len, dtype=jnp.int32)[None], (b, f_len))
         h = L.apply_norm(x, p["ln1"], cfg.norm)
         q, k, v = L.attn_qkv(p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
-                             cfg.head_dim, pos, cfg.rope_theta, cfg.qk_norm)
+                             cfg.head_dim, pos, cfg.rope_theta, cfg.qk_norm,
+                             rotary_dim=cfg.rotary_dim)
         o = L.flash_attention(q, k, v, pos, pos, softcap=cfg.attn_logit_softcap,
                               chunk=min(1024, f_len), unroll=True, causal=False)
         x = x + L.attn_out(p["attn"], o)

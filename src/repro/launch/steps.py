@@ -151,7 +151,7 @@ def _block_with_kv(cfg, p, x, positions, win, attn_impl):
     h = L.apply_norm(x, p["ln1"], cfg.norm)
     q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
                                  cfg.head_dim, positions, cfg.rope_theta,
-                                 cfg.qk_norm)
+                                 cfg.qk_norm, rotary_dim=cfg.rotary_dim)
     o = _attn_for_prefill(cfg, q, k_new, v_new, positions, win, attn_impl)
     x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o))
     h = L.apply_norm(x, p["ln2"], cfg.norm)

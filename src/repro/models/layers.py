@@ -57,9 +57,17 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               rotary_dim: int) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+
+    Rotates the leading ``rotary_dim`` channels of each head — channel i
+    paired with i + rotary_dim/2 at frequency theta^(-2i/rotary_dim) — and
+    passes the rest through unchanged (partial rotary)."""
     hd = x.shape[-1]
+    if rotary_dim < hd:
+        rot = apply_rope(x[..., :rotary_dim], positions, theta, rotary_dim)
+        return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)
     half = hd // 2
     freqs = rope_freqs(hd, theta)                                # (half,)
     ang = positions[..., None].astype(jnp.float32) * freqs       # (..., S, half)
@@ -268,8 +276,10 @@ def init_attn(key: jax.Array, d: int, n_heads: int, n_kv: int, hd: int,
 
 
 def attn_qkv(p: dict, x: jax.Array, n_heads: int, n_kv: int, hd: int,
-             positions: jax.Array, theta: float, qk_norm: bool = False,
-             rope: bool = True):
+             positions: jax.Array, theta: float, qk_norm: bool = False, *,
+             rotary_dim: int):
+    """q, k, v of one layer, q and k rotated over their leading
+    ``rotary_dim`` channels (``ModelConfig.rotary_dim``)."""
     b, s, _ = x.shape
     q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, n_heads, hd)
     k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, n_kv, hd)
@@ -277,9 +287,9 @@ def attn_qkv(p: dict, x: jax.Array, n_heads: int, n_kv: int, hd: int,
     if qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
-    if rope:
-        q = apply_rope(q, positions, theta)
-        k = apply_rope(k, positions, theta)
+    with jax.named_scope("rope"):
+        q = apply_rope(q, positions, theta, rotary_dim)
+        k = apply_rope(k, positions, theta, rotary_dim)
     return q, k, v
 
 

@@ -172,7 +172,8 @@ def _attn_prefill(cfg, params, x, positions, cache, attn_impl, wins, kinds):
     def run_block(i_attn, p, h, win, ck, cv):
         hh = L.apply_norm(h, p["ln1"], cfg.norm)
         q, k_new, v_new = L.attn_qkv(p["attn"], hh, cfg.n_heads, cfg.n_kv_heads,
-                                     cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm)
+                                     cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm,
+                                     rotary_dim=cfg.rotary_dim)
         ck, cv = _write_kv(ck, cv, i_attn, k_new, v_new, start)
         if joint_over_cache:
             k_pos = _cache_kpos(smax, start, h.shape[1])
@@ -257,7 +258,8 @@ def _rglru_prefill(cfg, params, x, positions, cache, attn_impl, n_valid=None):
             smax = ck.shape[2]
             hh = L.apply_norm(x, p["ln1"], cfg.norm)
             q, k_new, v_new = L.attn_qkv(p["attn"], hh, cfg.n_heads, cfg.n_kv_heads,
-                                         cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm)
+                                         cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm,
+                                         rotary_dim=cfg.rotary_dim)
             ck, cv = _write_kv(ck, cv, ai, k_new, v_new, start)
             if smax <= 2048:  # joint continuation over cache (engine path)
                 k_pos = _cache_kpos(smax, start, x.shape[1])
@@ -339,7 +341,8 @@ def _decode_attention(cfg, p, x, positions, k_cache, v_cache, win, lengths):
     smax = k_cache.shape[1]
     h = L.apply_norm(x, p["ln1"], cfg.norm)
     q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
-                                 cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm)
+                                 cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm,
+                                 rotary_dim=cfg.rotary_dim)
     bidx = jnp.arange(b)
     static_win = cfg.window if cfg.attn_kind in ("swa", "hybrid_rglru") else None
     ring = (static_win is not None and smax <= ring_len(cfg)

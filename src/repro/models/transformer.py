@@ -183,7 +183,8 @@ def attn_block_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Arr
     Returns (x_out, updated (k, v) or None)."""
     h = L.apply_norm(x, p["ln1"], cfg.norm)
     q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
-                                 cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm)
+                                 cfg.head_dim, positions, cfg.rope_theta, cfg.qk_norm,
+                                 rotary_dim=cfg.rotary_dim)
     updated = None
     if kv_write is None:
         k_all, v_all, k_pos = k_new, v_new, positions
@@ -499,7 +500,8 @@ def encode(cfg, params, frames, attn_impl="auto", scan_layers=True,
         p = AS.constrain_block(p, "enc_blocks")
         hh = L.apply_norm(h, p["ln1"], cfg.norm)
         q, k, v = L.attn_qkv(p["attn"], hh, cfg.n_heads, cfg.n_kv_heads,
-                             cfg.head_dim, pos, cfg.rope_theta, cfg.qk_norm)
+                             cfg.head_dim, pos, cfg.rope_theta, cfg.qk_norm,
+                             rotary_dim=cfg.rotary_dim)
         o = _self_attention(cfg, q, k, v, pos, pos, None, attn_impl,
                             unroll_probe, causal=False)
         h = h + L.attn_out(p["attn"], o)

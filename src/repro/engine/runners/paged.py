@@ -47,6 +47,24 @@ from repro.models import transformer as T
 from repro.models.model_factory import ModelBundle
 
 
+def page_rows(bt, ps: int):
+    """Row of each token slot of the pages ``bt`` (..., Pb) within one layer
+    of the pool, (..., Pb, ps): slot j of a run holds its token j."""
+    return bt[..., None] * ps + jnp.arange(ps, dtype=jnp.int32)
+
+
+def layer_rows(pool, li: int, rows):
+    """The token rows ``rows`` (any shape) of layer ``li`` of the stacked
+    pool (layers, pages, ps, Hkv, hd), as (*rows.shape, Hkv, hd): one gather
+    from the pool seen as (layers·pages·ps, Hkv, hd), which moves only those
+    rows. Both phases read KV this way. On TPU the layer slice
+    (``pool[li]``) compiles as a copy of the layer's whole pool, and a gather
+    of whole pages (``pool[li, bt]``, or the pool seen as
+    (layers·pages, ps, Hkv, hd)) as a split of the whole pool."""
+    flat = pool.reshape(-1, *pool.shape[3:])
+    return flat[li * pool.shape[1] * pool.shape[2] + rows]
+
+
 class PagedRunner:
     """Family facade: shared state + phase delegation (public API of the
     pre-registry PagedRunner, preserved verbatim)."""
@@ -285,16 +303,6 @@ class PagedPrefillRunner:
         total = pb * ps
         from repro.engine.sampling import greedy_core, sample_core
 
-        def entry_runs(pool, li, run_rows):
-            # each entry's pages, side by side on one key axis
-            # (1, Sb·total, Hkv, hd): one gather of token rows from the pool
-            # seen as (layers·pages·ps, Hkv, hd); its size does not grow
-            # with Sb or Pb. Gathering whole 512-token pages
-            # (pool[li, bt_seq]) compiles on TPU as a split of the WHOLE
-            # pool, every layer's pages, on every layer.
-            rows = pool.reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-            return rows[li * pool.shape[1] * ps + run_rows][None]
-
         def run(params, tokens, positions, page, slot, seg, bt_seq,
                 final_idx, temps, top_ps, key, k_pool, v_pool):
             # the packed tokens are one batch row (1, Tb, D) of queries; the
@@ -312,9 +320,9 @@ class PagedPrefillRunner:
             # (entry Sb) match no key and leave with a uniform, finite mix.
             own = ((seg[:, None] == (kj // total)[None])
                    & (kpos[None] <= positions[:, None]))[None]  # (1,Tb,K)
-            # key j's row in one layer of the pool
-            run_rows = (bt_seq[:, :, None] * ps
-                        + jnp.arange(ps, dtype=jnp.int32)).reshape(-1)
+            # key j's row in one layer of the pool: each entry's pages, side
+            # by side on one key axis (1, Sb·total, Hkv, hd)
+            run_rows = page_rows(bt_seq, ps).reshape(-1)
             for li in range(cfg.n_layers):
                 p = jax.tree.map(lambda a: a[li], params["blocks"])
                 with jax.named_scope("attention"):
@@ -330,8 +338,8 @@ class PagedPrefillRunner:
                     k_pool = k_pool.at[li, page, slot].set(k_new[0])
                     v_pool = v_pool.at[li, page, slot].set(v_new[0])
                 with jax.named_scope("kv_gather"):
-                    k_seq = entry_runs(k_pool, li, run_rows)
-                    v_seq = entry_runs(v_pool, li, run_rows)
+                    k_seq = layer_rows(k_pool, li, run_rows)[None]
+                    v_seq = layer_rows(v_pool, li, run_rows)[None]
                 with jax.named_scope("attention"):
                     mask = own & (kpos[None, None]
                                   > (pos2[:, :, None] - wins[li]))
@@ -442,6 +450,8 @@ class PagedDecodeRunner:
         bidx = jnp.arange(b)
         page = bt[bidx, (lengths - 1) // ps]
         slot = (lengths - 1) % ps
+        # each row's page run in one layer of the pool: (B, Pb·ps)
+        rows = page_rows(bt, ps).reshape(b, -1)
         for li in range(cfg.n_layers):
             p = jax.tree.map(lambda a: a[li], params["blocks"])
             with jax.named_scope("attention"):
@@ -453,13 +463,14 @@ class PagedDecodeRunner:
             with jax.named_scope("kv_scatter"):
                 k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
                 v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
+            with jax.named_scope("kv_gather"):
+                k_run = layer_rows(k_pool, li, rows)   # (B, Pb·ps, Hkv, hd)
+                v_run = layer_rows(v_pool, li, rows)
             win = wins[li] if wins[li] < T.GLOBAL_WINDOW else None
-            # the page-run gather inside is scoped kv_gather by the kernel
             with jax.named_scope("attention"):
-                o = KREF.paged_attention_ref(q[:, 0], k_pool[li], v_pool[li],
-                                             bt, lengths,
-                                             softcap=cfg.attn_logit_softcap,
-                                             window=win)
+                o = KREF.decode_attention_ref(q[:, 0], k_run, v_run, lengths,
+                                              softcap=cfg.attn_logit_softcap,
+                                              window=win)
                 x = x + S._post_attn(cfg, p,
                                      L.attn_out(p["attn"], o[:, None]))
             with jax.named_scope("mlp"):

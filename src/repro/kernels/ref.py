@@ -24,16 +24,34 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     lengths: (B,) int32 — valid tokens per sequence (incl. current token).
     Returns (B, H, hd).
     """
+    b = q.shape[0]
+    _, p, hkv, hd = k_pages.shape
+    run = block_tables.shape[1] * p
+    with jax.named_scope("kv_gather"):
+        k = k_pages[block_tables].reshape(b, run, hkv, hd)  # (B, L, Hkv, hd)
+        v = v_pages[block_tables].reshape(b, run, hkv, hd)
+    return decode_attention_ref(q, k, v, lengths, softcap=softcap,
+                                window=window)
+
+
+def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                         lengths: jax.Array,
+                         softcap: Optional[float] = None,
+                         window: Optional[int] = None) -> jax.Array:
+    """Decode attention over each sequence's already gathered KV run.
+
+    q: (B, H, hd) — one query per sequence (position = lengths-1).
+    k / v: (B, L, Hkv, hd) — slot j holds the sequence's token j; slots at
+    or past its length are masked.
+    lengths: (B,) int32 — valid tokens per sequence (incl. current token).
+    Returns (B, H, hd).
+    """
     b, h, hd = q.shape
-    np_, p, hkv, _ = k_pages.shape
-    maxp = block_tables.shape[1]
+    n, hkv = k.shape[1], k.shape[2]
     g = h // hkv
     scale = 1.0 / math.sqrt(hd)
 
-    with jax.named_scope("kv_gather"):
-        k = k_pages[block_tables].reshape(b, maxp * p, hkv, hd)  # (B, L, Hkv, hd)
-        v = v_pages[block_tables].reshape(b, maxp * p, hkv, hd)
-    pos = jnp.arange(maxp * p, dtype=jnp.int32)[None, :]
+    pos = jnp.arange(n, dtype=jnp.int32)[None, :]
     valid = pos < lengths[:, None]
     if window is not None:
         valid &= pos > (lengths[:, None] - 1 - window)

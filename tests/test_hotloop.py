@@ -20,6 +20,8 @@ behind it: 1 sync. At K=1 the batch's pages are reserved one step ahead,
 so the third page (context 17) arrives mid-run, outgrows the block table's
 page bucket of 2, and the rebuild first drains the block in flight: 2.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,7 +30,11 @@ import pytest
 from repro.engine import EngineConfig, FlowServe, Request, SamplingParams
 from repro.engine.hotloop import DecodeHotState, pow2_bucket
 from repro.engine.kv_cache import PagedKVPool
+from repro.kernels import ref as KREF
 from repro.models import get_model
+from repro.models import layers as L
+from repro.models import serving as S
+from repro.models import transformer as T
 
 needs2 = pytest.mark.skipif(
     jax.device_count() < 2,
@@ -195,6 +201,80 @@ def test_warmup_precompiles_all_buckets(qwen):
     comps = te.run_to_completion()
     assert len(comps) == 3
     assert te.jit_compiles == compiles0    # steady serving never compiled
+
+
+# ---------------------------------------------------------------------------
+# Decode attention reads its own layer of the stacked pool
+# ---------------------------------------------------------------------------
+
+
+def _sliced_pool_decode_body(rt, params, tokens, bt, lengths, k_pool, v_pool):
+    """One decode step that hands each layer's pool, sliced out of the
+    stacked pool, to the paged-attention oracle: how the decode read KV
+    before it gathered token rows. The reference of the test below."""
+    cfg = rt.cfg
+    ps = rt.pool.page_size
+    b = tokens.shape[0]
+    x = T.embed(cfg, params, tokens[:, None])
+    pos = (lengths - 1)[:, None]
+    page = bt[jnp.arange(b), (lengths - 1) // ps]
+    slot = (lengths - 1) % ps
+    for li in range(cfg.n_layers):
+        p = jax.tree.map(lambda a: a[li], params["blocks"])
+        h = L.apply_norm(x, p["ln1"], cfg.norm)
+        q, k_new, v_new = L.attn_qkv(p["attn"], h, cfg.n_heads,
+                                     cfg.n_kv_heads, cfg.head_dim, pos,
+                                     cfg.rope_theta, cfg.qk_norm,
+                                     rotary_dim=cfg.rotary_dim)
+        k_pool = k_pool.at[li, page, slot].set(k_new[:, 0])
+        v_pool = v_pool.at[li, page, slot].set(v_new[:, 0])
+        win = rt._wins[li] if rt._wins[li] < T.GLOBAL_WINDOW else None
+        o = KREF.paged_attention_ref(q[:, 0], k_pool[li], v_pool[li], bt,
+                                     lengths, softcap=cfg.attn_logit_softcap,
+                                     window=win)
+        x = x + S._post_attn(cfg, p, L.attn_out(p["attn"], o[:, None]))
+        x = x + rt._mlp(p, x)
+    return T.unembed(cfg, params, x)[:, 0], k_pool, v_pool
+
+
+@pytest.fixture(scope="module", params=["qwen3-8b", "gemma2-9b"])
+def paged_model(request):
+    """A tiny qwen3 (GQA, qk-norm) and a tiny gemma2 (local window of 16
+    alternating with global layers, attention softcap)."""
+    bundle = get_model(request.param, smoke=True)
+    params = bundle.init_params(jax.random.PRNGKey(0), jnp.float32)
+    return bundle, params
+
+
+@pytest.mark.parametrize("pb,lengths", [(1, [8, 5, 1]), (4, [32, 19, 7])],
+                         ids=["pb1", "pb4"])
+def test_decode_attention_reads_its_own_layer(paged_model, pb, lengths):
+    """Every layer of the stacked pool holds its own random KV, and each
+    row's run is past the page's or window's end in places: the decode
+    step's logits and pools match the sliced-pool reference at float32.
+    Rows: three live ones with their own pages, the shorter ones padded
+    with the scratch page, and one padding row parked on the scratch page
+    (length 1), as the fused batch holds them (batch bucket 4)."""
+    bundle, params = paged_model
+    ps = 8
+    te = FlowServe(bundle, params, EngineConfig(n_pages=16, page_size=ps))
+    rt = te.runner
+    scratch = te.pool.scratch_page()
+    bt = np.full((4, pb), scratch, np.int32)
+    for i, n in enumerate(lengths):
+        pages = te.pool.alloc(-(-n // ps))
+        bt[i, :len(pages)] = pages
+    lens = jnp.asarray(lengths + [1], jnp.int32)
+    shape = te.pool.k.shape
+    k_pool = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+    v_pool = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+    tokens = jnp.asarray([11, 97, 305, 0], jnp.int32)
+    args = (params, tokens, jnp.asarray(bt), lens, k_pool, v_pool)
+    got = jax.jit(rt.decoder._decode_body)(*args)
+    want = jax.jit(functools.partial(_sliced_pool_decode_body, rt))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=0, atol=1e-6)
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
 
 
 # ---------------------------------------------------------------------------

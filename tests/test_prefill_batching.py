@@ -254,6 +254,32 @@ def test_ragged_dispatch_gathers_no_per_token_run(qwen):
     assert (1, sb * pb * ps, hkv, hd) in shapes
 
 
+def test_fused_decode_gathers_token_rows_not_layer_pools(qwen):
+    """At the horizon (K 8, Bb 4, Pb 4) no intermediate of the traced fused
+    decode holds one layer's pool (pages, ps, Hkv, hd), the pool seen as
+    whole pages (layers·pages, ps, Hkv, hd), nor whole pages of each row's
+    run (Bb, Pb, ps, Hkv, hd), while each row's run as token rows,
+    (Bb, Pb·ps, Hkv, hd), is there."""
+    bundle, params = qwen
+    cfg = bundle.cfg
+    te = FlowServe(bundle, params, EngineConfig(n_pages=16, page_size=8))
+    k, bb, pb, ps = 8, 4, 4, 8
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)      # noqa: E731
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)    # noqa: E731
+    pool = jax.ShapeDtypeStruct(te.pool.k.shape, te.pool.k.dtype)
+    fn = te.runner.decoder._decode_fused_fn(k, bb, pb)
+    jaxpr = jax.make_jaxpr(fn)(
+        params, i32(bb, pb), jax.ShapeDtypeStruct((bb,), jnp.bool_), f32(bb),
+        f32(bb), jax.random.PRNGKey(0), i32(bb), i32(bb), pool, pool)
+    shapes = set(_avals(jaxpr.jaxpr))
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    n_layers, pages = te.pool.k.shape[:2]
+    assert (pages, ps, hkv, hd) not in shapes
+    assert (n_layers * pages, ps, hkv, hd) not in shapes
+    assert (bb, pb, ps, hkv, hd) not in shapes
+    assert (bb, pb * ps, hkv, hd) in shapes
+
+
 # ---------------------------------------------------------------------------
 # Steady-state regression: 1 prefill dispatch / step, 0 recompiles
 # ---------------------------------------------------------------------------
